@@ -55,7 +55,6 @@ from repro.core.groups import PAD_COST, GroupSpec
 from repro.core.lbfgs import state_pspecs as lbfgs_pspecs
 from repro.core.regularizers import Regularizer
 from repro.sharding.partition import batch_solve_rules
-from repro.utils.compat import shard_map
 
 
 def problem_pspec(mesh: Mesh) -> PartitionSpec:
@@ -208,20 +207,20 @@ def _sharded_programs(mesh: Mesh, prob: DualProblem, opts: slv.SolveOptions):
     # leaf Cp carries the leading problem axis; geometry fields are static)
     # and degenerates to "no leaves" when padded is None (non-pallas).
     solve = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_solve, mesh=mesh, in_specs=arrs,
             out_specs=(lbfgs_pspecs(A), screening.state_pspecs(A), A, A),
             check_vma=False,
         )
     )
     init = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_init, mesh=mesh, in_specs=arrs + (A,), out_specs=ST,
             check_vma=False,
         )
     )
     rnd = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_round, mesh=mesh, in_specs=(ST,) + arrs + (A,),
             out_specs=ST, check_vma=False,
         )

@@ -658,7 +658,8 @@ def batch_round(state, C, a, b, row_mask, sqrt_g, prob, opts, padded=None):
     """Jitted single round over the batch (one launch per engine tick).
 
     ``padded`` as in :func:`init_batch_state` — the engine passes its
-    cached copy so the (largest-array) re-pad doesn't run every tick.
+    cached copy so the (largest-array) re-pad doesn't run every tick, or
+    None when padding would not change the cost.
     """
     if padded is None:
         padded = _prepare_padded(C, prob, opts)

@@ -85,6 +85,12 @@ VMEM_BUDGET_BYTES = 8 * 1024 * 1024  # one grid step's full working set
 # (scalar-prefetched) schedules instead of serializing DMA behind compute.
 COMPACT_PIPELINE_BUFFERS = 2
 
+
+# Mosaic parameters of the compact kernels: VMEM for two working sets.
+_COMPACT_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES,
+)
+
 # Above this fraction of live tiles the dense grid wins: compaction pays an
 # O(T) schedule build plus per-step partial-output traffic, while the dense
 # grid's only overhead for a skipped tile is an empty (DMA-elided) grid step.
@@ -174,21 +180,20 @@ def resolve_tile_l(L: int, g: int, tile_n: int, dtype_bytes: int = 4) -> int:
 def _gradpsi_tile(alpha, beta, c, tau, *, gamma: float):
     """Shared per-tile math: returns (T (TL, g, TN), psi_sum scalar).
 
-    ``tau`` is the per-group threshold row (TL,) — uniform for the paper's
-    group-sparse Psi, zero for pure-l2 (nonnegativity skipping), mixed for
-    elastic-net group weights (see core.regularizers).
+    ``tau`` is the per-group threshold column (TL, 1) — uniform for the
+    paper's group-sparse Psi, zero for pure-l2 (nonnegativity skipping),
+    mixed for elastic-net group weights (see core.regularizers).
     """
     f = alpha[:, :, None] + beta[None, None, :] - c
     fp = jnp.maximum(f, 0.0)
     zsq = jnp.sum(fp * fp, axis=1)                   # (TL, TN)
     z = jnp.sqrt(zsq)
-    tau_c = tau[:, None]                             # (TL, 1)
-    on = z > tau_c
+    on = z > tau                                     # tau (TL, 1)
     zs = jnp.where(on, z, 1.0)
-    s = jnp.where(on, 1.0 - tau_c / zs, 0.0)         # (TL, TN)
+    s = jnp.where(on, 1.0 - tau / zs, 0.0)           # (TL, TN)
     t = s[:, None, :] * fp * (1.0 / gamma)           # (TL, g, TN)
     # psi closed form (regularizers.psi_from_z)
-    mu_s_z = (tau_c / gamma) * s * zs                # mu_l*s*z, tau_l=mu_l*gamma
+    mu_s_z = (tau / gamma) * s * zs                  # mu_l*s*z, tau_l=mu_l*gamma
     psi = jnp.where(on, s * zs * zs / gamma * (1.0 - 0.5 * s) - mu_s_z, 0.0)
     return t, jnp.sum(psi)
 
@@ -204,6 +209,26 @@ def tau_row(tau, L: int) -> jnp.ndarray:
     return jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (L,))
 
 
+# -- Mosaic block layouts --------------------------------------------------------
+# Mosaic accepts a block only if its last two dims are multiples of (8, 128)
+# or span the whole array, and a rank-1 block only if it is a multiple of 128
+# or the whole array.  Everything indexed by group tile is therefore handed
+# to the kernels as (..., L_tiles, tile_l, k): the tile axis is squeezed by
+# the BlockSpec (``None``) and the (tile_l, k) block spans the trailing dims
+# for any tile_l.  Per-group rows become (tile_l, 1) columns, and per-tile
+# partial outputs get a unit second-minor axis, e.g. (L_tiles, 1, n).
+
+
+def group_tiles(x: jnp.ndarray, tile_l: int) -> jnp.ndarray:
+    """(..., L, k) -> (..., L_tiles, tile_l, k), one kernel block per tile."""
+    return x.reshape(x.shape[:-2] + (-1, tile_l, x.shape[-1]))
+
+
+def group_rows(x: jnp.ndarray, tile_l: int) -> jnp.ndarray:
+    """Per-group row (..., L) -> (..., L_tiles, tile_l, 1) column blocks."""
+    return group_tiles(x[..., None], tile_l)
+
+
 def _verdict_tile(z, k, o, act, da_plus, da_full, da_neg, db, sqrt_g, tau):
     """Per-tile screening verdicts (paper Eq. 6/7) from loaded VMEM arrays.
 
@@ -212,30 +237,25 @@ def _verdict_tile(z, k, o, act, da_plus, da_full, da_neg, db, sqrt_g, tau):
     identically-blocked operands, which is what makes the fused route's tile
     flags bitwise-equal to the two-launch route's.  ``z``/``k``/``o`` are
     (TL, TN) f32 bound tiles, ``act`` an int8 (TL, TN) persistent-set tile,
-    ``da_plus``/``da_full``/``da_neg``/``sqrt_g``/``tau`` (TL,) rows and
-    ``db`` a (TN,) column; returns (TL, TN) int32 verdicts.
+    ``da_plus``/``da_full``/``da_neg``/``sqrt_g``/``tau`` (TL, 1) per-group
+    columns and ``db`` a (TN,) row; returns (TL, TN) int32 verdicts.
     """
-    dap = da_plus[:, None]                            # (TL, 1)
-    daf = da_full[:, None]
-    dan = da_neg[:, None]
-    sg = sqrt_g[:, None]
-    tau_c = tau[:, None]                              # (TL, 1) per-group
     db_r = db[None, :]                                # (1, TN)
 
-    zbar = z + dap + sg * jnp.maximum(db_r, 0.0)
+    zbar = z + da_plus + sqrt_g * jnp.maximum(db_r, 0.0)
     zlow = (
         k
-        - daf
-        - sg * jnp.abs(db_r)
+        - da_full
+        - sqrt_g * jnp.abs(db_r)
         - o
-        - dan
-        - sg * jnp.maximum(-db_r, 0.0)
+        - da_neg
+        - sqrt_g * jnp.maximum(-db_r, 0.0)
     )
     active = act != 0
-    v = jnp.where(zbar <= tau_c, ZERO, CHECK)
+    v = jnp.where(zbar <= tau, ZERO, CHECK)
     v = jnp.where(active, ACTIVE, v)
     # lower bound can also certify non-zero outside N within this eval
-    v = jnp.where(jnp.logical_and(v == CHECK, zlow > tau_c), ACTIVE, v)
+    v = jnp.where(jnp.logical_and(v == CHECK, zlow > tau), ACTIVE, v)
     return v.astype(jnp.int32)
 
 
@@ -259,11 +279,11 @@ def _dense_kernel(flags_ref, alpha_ref, beta_ref, c_ref, tau_ref,
     @pl.when(flag != 0)
     def _compute():
         alpha = alpha_ref[...].astype(jnp.float32)       # (TL, g)
-        beta = beta_ref[...].astype(jnp.float32)         # (TN,)
+        beta = beta_ref[0].astype(jnp.float32)           # (TN,)
         c = c_ref[...].astype(jnp.float32)               # (TL, g, TN)
-        tau = tau_ref[...].astype(jnp.float32)           # (TL,)
+        tau = tau_ref[...].astype(jnp.float32)           # (TL, 1)
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)                # (TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]   # (1, TN)
 
@@ -304,7 +324,7 @@ def gradpsi_pallas(
     grid = (L // tile_l, n // tile_n)
     assert flags.shape == grid, (flags.shape, grid)
 
-    alpha_g = alpha.reshape(L, g)
+    alpha_g = alpha.reshape(grid[0], tile_l, g)
     C3 = C.reshape(L, g, n)
 
     def c_index(l, j, flags_ref):
@@ -317,14 +337,14 @@ def gradpsi_pallas(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j, f: (l, 0)),
-            pl.BlockSpec((tile_n,), lambda l, j, f: (j,)),
+            pl.BlockSpec((None, tile_l, g), lambda l, j, f: (l, 0, 0)),
+            pl.BlockSpec((1, tile_n), lambda l, j, f: (0, j)),
             pl.BlockSpec((tile_l, g, tile_n), c_index),
-            pl.BlockSpec((tile_l,), lambda l, j, f: (l,)),
+            pl.BlockSpec((None, tile_l, 1), lambda l, j, f: (l, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j, f: (l, 0)),
-            pl.BlockSpec((1, tile_n), lambda l, j, f: (l, j)),
+            pl.BlockSpec((None, tile_l, g), lambda l, j, f: (l, 0, 0)),
+            pl.BlockSpec((None, 1, tile_n), lambda l, j, f: (l, 0, j)),
             pl.BlockSpec((1, 1), lambda l, j, f: (0, 0)),
         ],
     )
@@ -333,14 +353,14 @@ def gradpsi_pallas(
         functools.partial(_dense_kernel, gamma=float(gamma)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((L, g), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], n), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(flags, alpha_g, beta, C3, tau_g)
+    )(flags, alpha_g, beta[None, :], C3, group_rows(tau_g, tile_l))
 
-    return ga_part.reshape(-1), jnp.sum(gb_part, axis=0), psi[0, 0]
+    return ga_part.reshape(-1), jnp.sum(gb_part[:, 0], axis=0), psi[0, 0]
 
 
 def build_tile_schedule(flags: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -373,20 +393,20 @@ def _compact_kernel(sched_ref, nact_ref, alpha_ref, beta_ref, c_ref, tau_ref,
 
     @pl.when(s == 0)
     def _init_steps():
-        steps_ref[0, 0] = 0
+        steps_ref[...] = jnp.zeros_like(steps_ref)
 
-    steps_ref[0, 0] += 1
+    steps_ref[...] += 1
 
     alpha = alpha_ref[...].astype(jnp.float32)           # (TL, g)
-    beta = beta_ref[...].astype(jnp.float32)             # (TN,)
+    beta = beta_ref[0].astype(jnp.float32)               # (TN,)
     c = c_ref[...].astype(jnp.float32)                   # (TL, g, TN)
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
     # per-step slots: every visited block is written exactly once, so no
     # cross-step accumulation state and no uninitialized revisits.
     ga_ref[...] = jnp.sum(t, axis=2)[None]               # (1, TL, g)
     gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]       # (1, TN)
-    psi_ref[0, 0] = psi
+    psi_ref[...] = jnp.reshape(psi, (1, 1))
 
 
 @functools.partial(
@@ -427,7 +447,7 @@ def gradpsi_pallas_compact(
     T = Lt * Nt
     assert sched.shape == (2, T), (sched.shape, (2, T))
 
-    alpha_g = alpha.reshape(L, g)
+    alpha_g = alpha.reshape(Lt, tile_l, g)
     C3 = C.reshape(L, g, n)
     num_active = num_active.astype(jnp.int32)
     nact = num_active.reshape(1)
@@ -437,16 +457,18 @@ def gradpsi_pallas_compact(
         num_scalar_prefetch=2,
         grid=(num_steps,),
         in_specs=[
-            pl.BlockSpec((tile_l, g), lambda s, sc, na: (sc[0, s], 0)),
-            pl.BlockSpec((tile_n,), lambda s, sc, na: (sc[1, s],)),
+            pl.BlockSpec((None, tile_l, g),
+                         lambda s, sc, na: (sc[0, s], 0, 0)),
+            pl.BlockSpec((1, tile_n), lambda s, sc, na: (0, sc[1, s])),
             pl.BlockSpec((tile_l, g, tile_n),
                          lambda s, sc, na: (sc[0, s], 0, sc[1, s])),
-            pl.BlockSpec((tile_l,), lambda s, sc, na: (sc[0, s],)),
+            pl.BlockSpec((None, tile_l, 1),
+                         lambda s, sc, na: (sc[0, s], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tile_l, g), lambda s, sc, na: (s, 0, 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s, sc, na: (s, 0)),
+            pl.BlockSpec((None, 1, tile_n), lambda s, sc, na: (s, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda s, sc, na: (s, 0, 0)),
             pl.BlockSpec((1, 1), lambda s, sc, na: (0, 0)),
         ],
     )
@@ -456,15 +478,14 @@ def gradpsi_pallas_compact(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((T, tile_l, g), jnp.float32),
-            jax.ShapeDtypeStruct((T, tile_n), jnp.float32),
-            jax.ShapeDtypeStruct((T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, tile_n), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            vmem_limit_bytes=COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES,
-        ),
+        compiler_params=_COMPACT_PARAMS,
         interpret=interpret,
-    )(sched, nact, alpha_g, beta, C3, tau_g)
+    )(sched, nact, alpha_g, beta[None, :], C3, group_rows(tau_g, tile_l))
+    gb_steps, psi_steps = gb_steps[:, 0], psi_steps[:, 0]
 
     # assemble: slots past num_active were never visited (garbage) — route
     # them to an out-of-range segment so the scatter drops them.
@@ -507,9 +528,9 @@ def _dense_kernel_batched(flags_ref, alpha_ref, beta_ref, c_ref, tau_ref,
         alpha = alpha_ref[0].astype(jnp.float32)         # (TL, g)
         beta = beta_ref[0].astype(jnp.float32)           # (TN,)
         c = c_ref[0].astype(jnp.float32)                 # (TL, g, TN)
-        tau = tau_ref[...].astype(jnp.float32)           # (TL,)
+        tau = tau_ref[...].astype(jnp.float32)           # (TL, 1)
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)[None]          # (1, TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, None, :]  # (1, 1, TN)
 
@@ -550,7 +571,7 @@ def gradpsi_pallas_batched(
     grid = (B, L // tile_l, n // tile_n)
     assert flags.shape == grid, (flags.shape, grid)
 
-    alpha_g = alpha.reshape(B, L, g)
+    alpha_g = alpha.reshape(B, grid[1], tile_l, g)
     C4 = C.reshape(B, L, g, n)
 
     def c_index(b, l, j, flags_ref):
@@ -563,14 +584,17 @@ def gradpsi_pallas_batched(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j, f: (b, l, 0)),
-            pl.BlockSpec((1, tile_n), lambda b, l, j, f: (b, j)),
+            pl.BlockSpec((1, None, tile_l, g),
+                         lambda b, l, j, f: (b, l, 0, 0)),
+            pl.BlockSpec((None, 1, tile_n), lambda b, l, j, f: (b, 0, j)),
             pl.BlockSpec((1, tile_l, g, tile_n), c_index),
-            pl.BlockSpec((tile_l,), lambda b, l, j, f: (l,)),
+            pl.BlockSpec((None, tile_l, 1), lambda b, l, j, f: (l, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j, f: (b, l, 0)),
-            pl.BlockSpec((1, 1, tile_n), lambda b, l, j, f: (b, l, j)),
+            pl.BlockSpec((1, None, tile_l, g),
+                         lambda b, l, j, f: (b, l, 0, 0)),
+            pl.BlockSpec((1, None, 1, tile_n),
+                         lambda b, l, j, f: (b, l, 0, j)),
             pl.BlockSpec((1, 1, 1), lambda b, l, j, f: (b, 0, 0)),
         ],
     )
@@ -579,16 +603,16 @@ def gradpsi_pallas_batched(
         functools.partial(_dense_kernel_batched, gamma=float(gamma)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, L, g), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid[1], n), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(flags, alpha_g, beta, C4, tau_g)
+    )(flags, alpha_g, beta[:, None, :], C4, group_rows(tau_g, tile_l))
 
     return (
         ga_part.reshape(B, -1),
-        jnp.sum(gb_part, axis=1),
+        jnp.sum(gb_part[:, :, 0], axis=1),
         psi[:, 0, 0],
     )
 
@@ -629,20 +653,20 @@ def _compact_kernel_batched(sched_ref, nact_ref, alpha_ref, beta_ref, c_ref,
 
     @pl.when(s == 0)
     def _init_steps():
-        steps_ref[0, 0] = 0
+        steps_ref[...] = jnp.zeros_like(steps_ref)
 
-    steps_ref[0, 0] += 1
+    steps_ref[...] += 1
 
     alpha = alpha_ref[0].astype(jnp.float32)             # (TL, g)
     beta = beta_ref[0].astype(jnp.float32)               # (TN,)
     c = c_ref[0].astype(jnp.float32)                     # (TL, g, TN)
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
     # per-step slots: every visited block is written exactly once, so no
     # cross-step accumulation state and no uninitialized revisits.
     ga_ref[...] = jnp.sum(t, axis=2)[None]               # (1, TL, g)
     gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]       # (1, TN)
-    psi_ref[0, 0] = psi
+    psi_ref[...] = jnp.reshape(psi, (1, 1))
 
 
 @functools.partial(
@@ -684,7 +708,7 @@ def gradpsi_pallas_compact_batched(
     BT = B * Lt * Nt
     assert sched.shape == (3, BT), (sched.shape, (3, BT))
 
-    alpha_g = alpha.reshape(B, L, g)
+    alpha_g = alpha.reshape(B, Lt, tile_l, g)
     C4 = C.reshape(B, L, g, n)
     num_active = num_active.astype(jnp.int32)
     nact = num_active.reshape(1)
@@ -694,17 +718,19 @@ def gradpsi_pallas_compact_batched(
         num_scalar_prefetch=2,
         grid=(num_steps,),
         in_specs=[
-            pl.BlockSpec((1, tile_l, g),
-                         lambda s, sc, na: (sc[0, s], sc[1, s], 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (sc[0, s], sc[2, s])),
+            pl.BlockSpec((1, None, tile_l, g),
+                         lambda s, sc, na: (sc[0, s], sc[1, s], 0, 0)),
+            pl.BlockSpec((None, 1, tile_n),
+                         lambda s, sc, na: (sc[0, s], 0, sc[2, s])),
             pl.BlockSpec((1, tile_l, g, tile_n),
                          lambda s, sc, na: (sc[0, s], sc[1, s], 0, sc[2, s])),
-            pl.BlockSpec((tile_l,), lambda s, sc, na: (sc[1, s],)),
+            pl.BlockSpec((None, tile_l, 1),
+                         lambda s, sc, na: (sc[1, s], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tile_l, g), lambda s, sc, na: (s, 0, 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s, sc, na: (s, 0)),
+            pl.BlockSpec((None, 1, tile_n), lambda s, sc, na: (s, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda s, sc, na: (s, 0, 0)),
             pl.BlockSpec((1, 1), lambda s, sc, na: (0, 0)),
         ],
     )
@@ -714,15 +740,14 @@ def gradpsi_pallas_compact_batched(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BT, tile_l, g), jnp.float32),
-            jax.ShapeDtypeStruct((BT, tile_n), jnp.float32),
-            jax.ShapeDtypeStruct((BT, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BT, 1, tile_n), jnp.float32),
+            jax.ShapeDtypeStruct((BT, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            vmem_limit_bytes=COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES,
-        ),
+        compiler_params=_COMPACT_PARAMS,
         interpret=interpret,
-    )(sched, nact, alpha_g, beta, C4, tau_g)
+    )(sched, nact, alpha_g, beta[:, None, :], C4, group_rows(tau_g, tile_l))
+    gb_steps, psi_steps = gb_steps[:, 0], psi_steps[:, 0]
 
     # assemble: slots past num_active were never visited (garbage) — route
     # them to an out-of-range segment so the scatter drops them.  Segments
@@ -766,14 +791,11 @@ def factorized_cost_tile(x, x_sq, y, y_sq):
     every output element sees the identical f32 operation sequence no matter
     how the caller tiles or chunks — the bitwise contract between the Pallas
     kernels and :meth:`repro.ot.geometry.SquaredL2Geometry.materialize`.
+    The leading axes are broadcast, never flattened: Mosaic cannot fold a
+    (TILE_L, g) block with g off the sublane tiling into one row axis.
     """
-    lead = x.shape[:-1]
-    x2 = x.reshape((-1, x.shape[-1]))
-    xy = jnp.sum(x2[:, None, :] * y[None, :, :], axis=-1)
-    c = jnp.maximum(
-        x_sq.reshape((-1,))[:, None] + y_sq[None, :] - 2.0 * xy, 0.0
-    )
-    return c.reshape(lead + (y.shape[0],))
+    xy = jnp.sum(x[..., :, None, :] * y, axis=-1)
+    return jnp.maximum(x_sq[..., None] + y_sq - 2.0 * xy, 0.0)
 
 
 def pick_tile_l_factorized(g: int, tile_n: int, d: int,
@@ -823,16 +845,16 @@ def _dense_kernel_fact(flags_ref, alpha_ref, beta_ref, x_ref, xsq_ref,
     @pl.when(flag != 0)
     def _compute_f():
         alpha = alpha_ref[...].astype(jnp.float32)       # (TL, g)
-        beta = beta_ref[...].astype(jnp.float32)         # (TN,)
+        beta = beta_ref[0].astype(jnp.float32)           # (TN,)
         c = factorized_cost_tile(
             x_ref[...].astype(jnp.float32),              # (TL, g, d)
             xsq_ref[...].astype(jnp.float32),            # (TL, g)
             y_ref[...].astype(jnp.float32),              # (TN, d)
-            ysq_ref[...].astype(jnp.float32),            # (TN,)
+            ysq_ref[0].astype(jnp.float32),              # (TN,)
         )
-        tau = tau_ref[...].astype(jnp.float32)           # (TL,)
+        tau = tau_ref[...].astype(jnp.float32)           # (TL, 1)
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)                # (TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]   # (1, TN)
 
@@ -878,9 +900,9 @@ def gradpsi_fact_pallas(
     grid = (L // tile_l, n // tile_n)
     assert flags.shape == grid, (flags.shape, grid)
 
-    alpha_g = alpha.reshape(L, g)
+    alpha_g = alpha.reshape(grid[0], tile_l, g)
     x3 = x.reshape(L, g, d)
-    xsq_g = x_sq.reshape(L, g)
+    xsq_g = x_sq.reshape(grid[0], tile_l, g)
 
     def y_index(l, j, flags_ref):
         active = flags_ref[l, j] != 0
@@ -888,23 +910,24 @@ def gradpsi_fact_pallas(
 
     def ysq_index(l, j, flags_ref):
         active = flags_ref[l, j] != 0
-        return (jnp.where(active, j, 0),)
+        return (0, jnp.where(active, j, 0))
 
+    row_g = pl.BlockSpec((None, tile_l, g), lambda l, j, f: (l, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j, f: (l, 0)),
-            pl.BlockSpec((tile_n,), lambda l, j, f: (j,)),
+            row_g,
+            pl.BlockSpec((1, tile_n), lambda l, j, f: (0, j)),
             pl.BlockSpec((tile_l, g, d), lambda l, j, f: (l, 0, 0)),
-            pl.BlockSpec((tile_l, g), lambda l, j, f: (l, 0)),
+            row_g,
             pl.BlockSpec((tile_n, d), y_index),
-            pl.BlockSpec((tile_n,), ysq_index),
-            pl.BlockSpec((tile_l,), lambda l, j, f: (l,)),
+            pl.BlockSpec((1, tile_n), ysq_index),
+            pl.BlockSpec((None, tile_l, 1), lambda l, j, f: (l, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j, f: (l, 0)),
-            pl.BlockSpec((1, tile_n), lambda l, j, f: (l, j)),
+            row_g,
+            pl.BlockSpec((None, 1, tile_n), lambda l, j, f: (l, 0, j)),
             pl.BlockSpec((1, 1), lambda l, j, f: (0, 0)),
         ],
     )
@@ -913,14 +936,15 @@ def gradpsi_fact_pallas(
         functools.partial(_dense_kernel_fact, gamma=float(gamma)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((L, g), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], n), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(flags, alpha_g, beta, x3, xsq_g, y, y_sq, tau_g)
+    )(flags, alpha_g, beta[None, :], x3, xsq_g, y, y_sq[None, :],
+      group_rows(tau_g, tile_l))
 
-    return ga_part.reshape(-1), jnp.sum(gb_part, axis=0), psi[0, 0]
+    return ga_part.reshape(-1), jnp.sum(gb_part[:, 0], axis=0), psi[0, 0]
 
 
 def _compact_kernel_fact(sched_ref, nact_ref, alpha_ref, beta_ref, x_ref,
@@ -931,23 +955,23 @@ def _compact_kernel_fact(sched_ref, nact_ref, alpha_ref, beta_ref, x_ref,
 
     @pl.when(s == 0)
     def _init_steps_f():
-        steps_ref[0, 0] = 0
+        steps_ref[...] = jnp.zeros_like(steps_ref)
 
-    steps_ref[0, 0] += 1
+    steps_ref[...] += 1
 
     alpha = alpha_ref[...].astype(jnp.float32)           # (TL, g)
-    beta = beta_ref[...].astype(jnp.float32)             # (TN,)
+    beta = beta_ref[0].astype(jnp.float32)               # (TN,)
     c = factorized_cost_tile(
         x_ref[...].astype(jnp.float32),
         xsq_ref[...].astype(jnp.float32),
         y_ref[...].astype(jnp.float32),
-        ysq_ref[...].astype(jnp.float32),
+        ysq_ref[0].astype(jnp.float32),
     )
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
     ga_ref[...] = jnp.sum(t, axis=2)[None]               # (1, TL, g)
     gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]       # (1, TN)
-    psi_ref[0, 0] = psi
+    psi_ref[...] = jnp.reshape(psi, (1, 1))
 
 
 @functools.partial(
@@ -991,29 +1015,33 @@ def gradpsi_fact_pallas_compact(
     T = Lt * Nt
     assert sched.shape == (2, T), (sched.shape, (2, T))
 
-    alpha_g = alpha.reshape(L, g)
+    alpha_g = alpha.reshape(Lt, tile_l, g)
     x3 = x.reshape(L, g, d)
-    xsq_g = x_sq.reshape(L, g)
+    xsq_g = x_sq.reshape(Lt, tile_l, g)
     num_active = num_active.astype(jnp.int32)
     nact = num_active.reshape(1)
     num_steps = jnp.maximum(num_active, 1)
 
+    row_g = pl.BlockSpec((None, tile_l, g),
+                         lambda s, sc, na: (sc[0, s], 0, 0))
+    col = pl.BlockSpec((1, tile_n), lambda s, sc, na: (0, sc[1, s]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(num_steps,),
         in_specs=[
-            pl.BlockSpec((tile_l, g), lambda s, sc, na: (sc[0, s], 0)),
-            pl.BlockSpec((tile_n,), lambda s, sc, na: (sc[1, s],)),
+            row_g,
+            col,
             pl.BlockSpec((tile_l, g, d), lambda s, sc, na: (sc[0, s], 0, 0)),
-            pl.BlockSpec((tile_l, g), lambda s, sc, na: (sc[0, s], 0)),
+            row_g,
             pl.BlockSpec((tile_n, d), lambda s, sc, na: (sc[1, s], 0)),
-            pl.BlockSpec((tile_n,), lambda s, sc, na: (sc[1, s],)),
-            pl.BlockSpec((tile_l,), lambda s, sc, na: (sc[0, s],)),
+            col,
+            pl.BlockSpec((None, tile_l, 1),
+                         lambda s, sc, na: (sc[0, s], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tile_l, g), lambda s, sc, na: (s, 0, 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s, sc, na: (s, 0)),
+            pl.BlockSpec((None, 1, tile_n), lambda s, sc, na: (s, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda s, sc, na: (s, 0, 0)),
             pl.BlockSpec((1, 1), lambda s, sc, na: (0, 0)),
         ],
     )
@@ -1023,15 +1051,15 @@ def gradpsi_fact_pallas_compact(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((T, tile_l, g), jnp.float32),
-            jax.ShapeDtypeStruct((T, tile_n), jnp.float32),
-            jax.ShapeDtypeStruct((T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, tile_n), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            vmem_limit_bytes=COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES,
-        ),
+        compiler_params=_COMPACT_PARAMS,
         interpret=interpret,
-    )(sched, nact, alpha_g, beta, x3, xsq_g, y, y_sq, tau_g)
+    )(sched, nact, alpha_g, beta[None, :], x3, xsq_g, y, y_sq[None, :],
+      group_rows(tau_g, tile_l))
+    gb_steps, psi_steps = gb_steps[:, 0], psi_steps[:, 0]
 
     idx = jnp.arange(T, dtype=jnp.int32)
     valid = idx < num_active
@@ -1076,9 +1104,9 @@ def _dense_kernel_fact_batched(flags_ref, alpha_ref, beta_ref, x_ref, xsq_ref,
             y_ref[0].astype(jnp.float32),                # (TN, d)
             ysq_ref[0].astype(jnp.float32),              # (TN,)
         )
-        tau = tau_ref[...].astype(jnp.float32)           # (TL,)
+        tau = tau_ref[...].astype(jnp.float32)           # (TL, 1)
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)[None]          # (1, TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, None, :]  # (1, 1, TN)
 
@@ -1121,9 +1149,9 @@ def gradpsi_fact_pallas_batched(
     grid = (B, L // tile_l, n // tile_n)
     assert flags.shape == grid, (flags.shape, grid)
 
-    alpha_g = alpha.reshape(B, L, g)
+    alpha_g = alpha.reshape(B, grid[1], tile_l, g)
     x4 = x.reshape(B, L, g, d)
-    xsq_g = x_sq.reshape(B, L, g)
+    xsq_g = x_sq.reshape(B, grid[1], tile_l, g)
 
     def y_index(b, l, j, flags_ref):
         active = flags_ref[b, l, j] != 0
@@ -1131,23 +1159,26 @@ def gradpsi_fact_pallas_batched(
 
     def ysq_index(b, l, j, flags_ref):
         active = flags_ref[b, l, j] != 0
-        return (b, jnp.where(active, j, 0))
+        return (b, 0, jnp.where(active, j, 0))
 
+    brow_g = pl.BlockSpec((1, None, tile_l, g),
+                          lambda b, l, j, f: (b, l, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j, f: (b, l, 0)),
-            pl.BlockSpec((1, tile_n), lambda b, l, j, f: (b, j)),
+            brow_g,
+            pl.BlockSpec((None, 1, tile_n), lambda b, l, j, f: (b, 0, j)),
             pl.BlockSpec((1, tile_l, g, d), lambda b, l, j, f: (b, l, 0, 0)),
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j, f: (b, l, 0)),
+            brow_g,
             pl.BlockSpec((1, tile_n, d), y_index),
-            pl.BlockSpec((1, tile_n), ysq_index),
-            pl.BlockSpec((tile_l,), lambda b, l, j, f: (l,)),
+            pl.BlockSpec((None, 1, tile_n), ysq_index),
+            pl.BlockSpec((None, tile_l, 1), lambda b, l, j, f: (l, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j, f: (b, l, 0)),
-            pl.BlockSpec((1, 1, tile_n), lambda b, l, j, f: (b, l, j)),
+            brow_g,
+            pl.BlockSpec((1, None, 1, tile_n),
+                         lambda b, l, j, f: (b, l, 0, j)),
             pl.BlockSpec((1, 1, 1), lambda b, l, j, f: (b, 0, 0)),
         ],
     )
@@ -1156,16 +1187,17 @@ def gradpsi_fact_pallas_batched(
         functools.partial(_dense_kernel_fact_batched, gamma=float(gamma)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, L, g), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid[1], n), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(flags, alpha_g, beta, x4, xsq_g, y, y_sq, tau_g)
+    )(flags, alpha_g, beta[:, None, :], x4, xsq_g, y, y_sq[:, None, :],
+      group_rows(tau_g, tile_l))
 
     return (
         ga_part.reshape(B, -1),
-        jnp.sum(gb_part, axis=1),
+        jnp.sum(gb_part[:, :, 0], axis=1),
         psi[:, 0, 0],
     )
 
@@ -1178,9 +1210,9 @@ def _compact_kernel_fact_batched(sched_ref, nact_ref, alpha_ref, beta_ref,
 
     @pl.when(s == 0)
     def _init_steps_fb():
-        steps_ref[0, 0] = 0
+        steps_ref[...] = jnp.zeros_like(steps_ref)
 
-    steps_ref[0, 0] += 1
+    steps_ref[...] += 1
 
     alpha = alpha_ref[0].astype(jnp.float32)             # (TL, g)
     beta = beta_ref[0].astype(jnp.float32)               # (TN,)
@@ -1190,11 +1222,11 @@ def _compact_kernel_fact_batched(sched_ref, nact_ref, alpha_ref, beta_ref,
         y_ref[0].astype(jnp.float32),
         ysq_ref[0].astype(jnp.float32),
     )
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
     ga_ref[...] = jnp.sum(t, axis=2)[None]               # (1, TL, g)
     gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]       # (1, TN)
-    psi_ref[0, 0] = psi
+    psi_ref[...] = jnp.reshape(psi, (1, 1))
 
 
 @functools.partial(
@@ -1238,33 +1270,36 @@ def gradpsi_fact_pallas_compact_batched(
     BT = B * Lt * Nt
     assert sched.shape == (3, BT), (sched.shape, (3, BT))
 
-    alpha_g = alpha.reshape(B, L, g)
+    alpha_g = alpha.reshape(B, Lt, tile_l, g)
     x4 = x.reshape(B, L, g, d)
-    xsq_g = x_sq.reshape(B, L, g)
+    xsq_g = x_sq.reshape(B, Lt, tile_l, g)
     num_active = num_active.astype(jnp.int32)
     nact = num_active.reshape(1)
     num_steps = jnp.maximum(num_active, 1)
 
+    brow_g = pl.BlockSpec((1, None, tile_l, g),
+                          lambda s, sc, na: (sc[0, s], sc[1, s], 0, 0))
+    bcol = pl.BlockSpec((None, 1, tile_n),
+                        lambda s, sc, na: (sc[0, s], 0, sc[2, s]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(num_steps,),
         in_specs=[
-            pl.BlockSpec((1, tile_l, g),
-                         lambda s, sc, na: (sc[0, s], sc[1, s], 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (sc[0, s], sc[2, s])),
+            brow_g,
+            bcol,
             pl.BlockSpec((1, tile_l, g, d),
                          lambda s, sc, na: (sc[0, s], sc[1, s], 0, 0)),
-            pl.BlockSpec((1, tile_l, g),
-                         lambda s, sc, na: (sc[0, s], sc[1, s], 0)),
+            brow_g,
             pl.BlockSpec((1, tile_n, d),
                          lambda s, sc, na: (sc[0, s], sc[2, s], 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (sc[0, s], sc[2, s])),
-            pl.BlockSpec((tile_l,), lambda s, sc, na: (sc[1, s],)),
+            bcol,
+            pl.BlockSpec((None, tile_l, 1),
+                         lambda s, sc, na: (sc[1, s], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tile_l, g), lambda s, sc, na: (s, 0, 0)),
-            pl.BlockSpec((1, tile_n), lambda s, sc, na: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s, sc, na: (s, 0)),
+            pl.BlockSpec((None, 1, tile_n), lambda s, sc, na: (s, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda s, sc, na: (s, 0, 0)),
             pl.BlockSpec((1, 1), lambda s, sc, na: (0, 0)),
         ],
     )
@@ -1274,15 +1309,15 @@ def gradpsi_fact_pallas_compact_batched(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BT, tile_l, g), jnp.float32),
-            jax.ShapeDtypeStruct((BT, tile_n), jnp.float32),
-            jax.ShapeDtypeStruct((BT, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BT, 1, tile_n), jnp.float32),
+            jax.ShapeDtypeStruct((BT, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            vmem_limit_bytes=COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES,
-        ),
+        compiler_params=_COMPACT_PARAMS,
         interpret=interpret,
-    )(sched, nact, alpha_g, beta, x4, xsq_g, y, y_sq, tau_g)
+    )(sched, nact, alpha_g, beta[:, None, :], x4, xsq_g, y, y_sq[:, None, :],
+      group_rows(tau_g, tile_l))
+    gb_steps, psi_steps = gb_steps[:, 0], psi_steps[:, 0]
 
     idx = jnp.arange(BT, dtype=jnp.int32)
     valid = idx < num_active
@@ -1334,22 +1369,22 @@ def _fused_dense_kernel(alpha_ref, beta_ref, c_ref, tau_ref, z_ref, k_ref,
 
     gb_ref[...] = jnp.zeros_like(gb_ref)
 
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     v = _verdict_tile(
         z_ref[...], k_ref[...], o_ref[...], act_ref[...],
         dap_ref[...], daf_ref[...], dan_ref[...],
-        db_ref[...], sg_ref[...], tau,
+        db_ref[0], sg_ref[...], tau,
     )
     flag = jnp.any(v != ZERO).astype(jnp.int32)
-    flag_ref[0, 0] = flag
+    flag_ref[...] = jnp.reshape(flag, (1, 1))
 
     @pl.when(flag != 0)
     def _compute_fu():
         alpha = alpha_ref[...].astype(jnp.float32)       # (TL, g)
-        beta = beta_ref[...].astype(jnp.float32)         # (TN,)
+        beta = beta_ref[0].astype(jnp.float32)           # (TN,)
         c = c_ref[...].astype(jnp.float32)               # (TL, g, TN)
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)                # (TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]   # (1, TN)
 
@@ -1400,18 +1435,21 @@ def gradpsi_fused_pallas(
     grid = (L // tile_l, n // tile_n)
     assert z.shape == (L, n), (z.shape, (L, n))
 
-    alpha_g = alpha.reshape(L, g)
+    alpha_g = alpha.reshape(grid[0], tile_l, g)
     C3 = C.reshape(L, g, n)
+    rows = functools.partial(group_rows, tile_l=tile_l)
+    tiles = functools.partial(group_tiles, tile_l=tile_l)
 
-    row = pl.BlockSpec((tile_l,), lambda l, j: (l,))
-    col = pl.BlockSpec((tile_n,), lambda l, j: (j,))
-    mat = pl.BlockSpec((tile_l, tile_n), lambda l, j: (l, j))
+    row = pl.BlockSpec((None, tile_l, 1), lambda l, j: (l, 0, 0))
+    row_g = pl.BlockSpec((None, tile_l, g), lambda l, j: (l, 0, 0))
+    col = pl.BlockSpec((1, tile_n), lambda l, j: (0, j))
+    mat = pl.BlockSpec((None, tile_l, tile_n), lambda l, j: (l, 0, j))
 
     ga_part, gb_part, psi, flags = pl.pallas_call(
         functools.partial(_fused_dense_kernel, gamma=float(gamma)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j: (l, 0)),        # alpha
+            row_g,                                                 # alpha
             col,                                                   # beta
             pl.BlockSpec((tile_l, g, tile_n), lambda l, j: (l, 0, j)),  # C
             row,                                                   # tau
@@ -1421,22 +1459,24 @@ def gradpsi_fused_pallas(
             row,                                                   # sqrt_g
         ],
         out_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j: (l, 0)),
-            pl.BlockSpec((1, tile_n), lambda l, j: (l, j)),
+            row_g,
+            pl.BlockSpec((None, 1, tile_n), lambda l, j: (l, 0, j)),
             pl.BlockSpec((1, 1), lambda l, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda l, j: (l, j)),
+            pl.BlockSpec((None, None, 1, 1), lambda l, j: (l, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((L, g), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], n), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(alpha_g, beta, C3, tau_g, z, k, o, active.astype(jnp.int8),
-      da_plus, da_full, da_neg, db, sqrt_g)
+    )(alpha_g, beta[None, :], C3, rows(tau_g), tiles(z), tiles(k), tiles(o),
+      tiles(active.astype(jnp.int8)), rows(da_plus), rows(da_full),
+      rows(da_neg), db[None, :], rows(sqrt_g))
 
-    return ga_part.reshape(-1), jnp.sum(gb_part, axis=0), psi[0, 0], flags
+    return (ga_part.reshape(-1), jnp.sum(gb_part[:, 0], axis=0), psi[0, 0],
+            flags[:, :, 0, 0])
 
 
 def _fused_dense_kernel_batched(alpha_ref, beta_ref, c_ref, tau_ref, z_ref,
@@ -1457,14 +1497,14 @@ def _fused_dense_kernel_batched(alpha_ref, beta_ref, c_ref, tau_ref, z_ref,
 
     gb_ref[...] = jnp.zeros_like(gb_ref)
 
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     v = _verdict_tile(
         z_ref[0], k_ref[0], o_ref[0], act_ref[0],
         dap_ref[0], daf_ref[0], dan_ref[0],
         db_ref[0], sg_ref[0], tau,
     )
     flag = jnp.any(v != ZERO).astype(jnp.int32)
-    flag_ref[0, 0, 0] = flag
+    flag_ref[...] = jnp.reshape(flag, (1, 1))
 
     @pl.when(flag != 0)
     def _compute_fub():
@@ -1472,7 +1512,7 @@ def _fused_dense_kernel_batched(alpha_ref, beta_ref, c_ref, tau_ref, z_ref,
         beta = beta_ref[0].astype(jnp.float32)           # (TN,)
         c = c_ref[0].astype(jnp.float32)                 # (TL, g, TN)
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)[None]          # (1, TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, None, :]  # (1, 1, TN)
 
@@ -1520,48 +1560,54 @@ def gradpsi_fused_pallas_batched(
     grid = (B, L // tile_l, n // tile_n)
     assert z.shape == (B, L, n), (z.shape, (B, L, n))
 
-    alpha_g = alpha.reshape(B, L, g)
+    alpha_g = alpha.reshape(B, grid[1], tile_l, g)
     C4 = C.reshape(B, L, g, n)
+    rows = functools.partial(group_rows, tile_l=tile_l)
+    tiles = functools.partial(group_tiles, tile_l=tile_l)
 
-    brow = pl.BlockSpec((1, tile_l), lambda b, l, j: (b, l))
-    bcol = pl.BlockSpec((1, tile_n), lambda b, l, j: (b, j))
-    bmat = pl.BlockSpec((1, tile_l, tile_n), lambda b, l, j: (b, l, j))
+    brow = pl.BlockSpec((1, None, tile_l, 1), lambda b, l, j: (b, l, 0, 0))
+    brow_g = pl.BlockSpec((1, None, tile_l, g), lambda b, l, j: (b, l, 0, 0))
+    bcol = pl.BlockSpec((None, 1, tile_n), lambda b, l, j: (b, 0, j))
+    bmat = pl.BlockSpec((1, None, tile_l, tile_n),
+                        lambda b, l, j: (b, l, 0, j))
 
     ga_part, gb_part, psi, flags = pl.pallas_call(
         functools.partial(_fused_dense_kernel_batched, gamma=float(gamma)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j: (b, l, 0)),  # alpha
+            brow_g,                                                   # alpha
             bcol,                                                     # beta
             pl.BlockSpec((1, tile_l, g, tile_n),
                          lambda b, l, j: (b, l, 0, j)),               # C
-            pl.BlockSpec((tile_l,), lambda b, l, j: (l,)),            # tau
+            pl.BlockSpec((None, tile_l, 1), lambda b, l, j: (l, 0, 0)),  # tau
             bmat, bmat, bmat, bmat,                                   # z k o act
             brow, brow, brow,                                         # da norms
             bcol,                                                     # db
             brow,                                                     # sqrt_g
         ],
         out_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j: (b, l, 0)),
-            pl.BlockSpec((1, 1, tile_n), lambda b, l, j: (b, l, j)),
+            brow_g,
+            pl.BlockSpec((1, None, 1, tile_n), lambda b, l, j: (b, l, 0, j)),
             pl.BlockSpec((1, 1, 1), lambda b, l, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, l, j: (b, l, j)),
+            pl.BlockSpec((None, None, None, 1, 1),
+                         lambda b, l, j: (b, l, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, L, g), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid[1], n), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(alpha_g, beta, C4, tau_g, z, k, o, active.astype(jnp.int8),
-      da_plus, da_full, da_neg, db, sqrt_g)
+    )(alpha_g, beta[:, None, :], C4, rows(tau_g), tiles(z), tiles(k),
+      tiles(o), tiles(active.astype(jnp.int8)), rows(da_plus),
+      rows(da_full), rows(da_neg), db[:, None, :], rows(sqrt_g))
 
     return (
         ga_part.reshape(B, -1),
-        jnp.sum(gb_part, axis=1),
+        jnp.sum(gb_part[:, :, 0], axis=1),
         psi[:, 0, 0],
-        flags,
+        flags[..., 0, 0],
     )
 
 
@@ -1582,27 +1628,27 @@ def _fused_fact_kernel(alpha_ref, beta_ref, x_ref, xsq_ref, y_ref, ysq_ref,
 
     gb_ref[...] = jnp.zeros_like(gb_ref)
 
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     v = _verdict_tile(
         z_ref[...], k_ref[...], o_ref[...], act_ref[...],
         dap_ref[...], daf_ref[...], dan_ref[...],
-        db_ref[...], sg_ref[...], tau,
+        db_ref[0], sg_ref[...], tau,
     )
     flag = jnp.any(v != ZERO).astype(jnp.int32)
-    flag_ref[0, 0] = flag
+    flag_ref[...] = jnp.reshape(flag, (1, 1))
 
     @pl.when(flag != 0)
     def _compute_ff():
         alpha = alpha_ref[...].astype(jnp.float32)       # (TL, g)
-        beta = beta_ref[...].astype(jnp.float32)         # (TN,)
+        beta = beta_ref[0].astype(jnp.float32)           # (TN,)
         c = factorized_cost_tile(
             x_ref[...].astype(jnp.float32),              # (TL, g, d)
             xsq_ref[...].astype(jnp.float32),            # (TL, g)
             y_ref[...].astype(jnp.float32),              # (TN, d)
-            ysq_ref[...].astype(jnp.float32),            # (TN,)
+            ysq_ref[0].astype(jnp.float32),              # (TN,)
         )
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)                # (TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, :]   # (1, TN)
 
@@ -1655,14 +1701,16 @@ def gradpsi_fused_fact_pallas(
     grid = (L // tile_l, n // tile_n)
     assert z.shape == (L, n), (z.shape, (L, n))
 
-    alpha_g = alpha.reshape(L, g)
+    alpha_g = alpha.reshape(grid[0], tile_l, g)
     x3 = x.reshape(L, g, d)
-    xsq_g = x_sq.reshape(L, g)
+    xsq_g = x_sq.reshape(grid[0], tile_l, g)
+    rows = functools.partial(group_rows, tile_l=tile_l)
+    tiles = functools.partial(group_tiles, tile_l=tile_l)
 
-    row = pl.BlockSpec((tile_l,), lambda l, j: (l,))
-    row_g = pl.BlockSpec((tile_l, g), lambda l, j: (l, 0))
-    col = pl.BlockSpec((tile_n,), lambda l, j: (j,))
-    mat = pl.BlockSpec((tile_l, tile_n), lambda l, j: (l, j))
+    row = pl.BlockSpec((None, tile_l, 1), lambda l, j: (l, 0, 0))
+    row_g = pl.BlockSpec((None, tile_l, g), lambda l, j: (l, 0, 0))
+    col = pl.BlockSpec((1, tile_n), lambda l, j: (0, j))
+    mat = pl.BlockSpec((None, tile_l, tile_n), lambda l, j: (l, 0, j))
 
     ga_part, gb_part, psi, flags = pl.pallas_call(
         functools.partial(_fused_fact_kernel, gamma=float(gamma)),
@@ -1681,23 +1729,24 @@ def gradpsi_fused_fact_pallas(
             row,                                                   # sqrt_g
         ],
         out_specs=[
-            pl.BlockSpec((tile_l, g), lambda l, j: (l, 0)),
-            pl.BlockSpec((1, tile_n), lambda l, j: (l, j)),
+            row_g,
+            pl.BlockSpec((None, 1, tile_n), lambda l, j: (l, 0, j)),
             pl.BlockSpec((1, 1), lambda l, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda l, j: (l, j)),
+            pl.BlockSpec((None, None, 1, 1), lambda l, j: (l, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((L, g), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], n), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(alpha_g, beta, x3, xsq_g, y, y_sq, tau_g,
-      z, k, o, active.astype(jnp.int8),
-      da_plus, da_full, da_neg, db, sqrt_g)
+    )(alpha_g, beta[None, :], x3, xsq_g, y, y_sq[None, :], rows(tau_g),
+      tiles(z), tiles(k), tiles(o), tiles(active.astype(jnp.int8)),
+      rows(da_plus), rows(da_full), rows(da_neg), db[None, :], rows(sqrt_g))
 
-    return ga_part.reshape(-1), jnp.sum(gb_part, axis=0), psi[0, 0], flags
+    return (ga_part.reshape(-1), jnp.sum(gb_part[:, 0], axis=0), psi[0, 0],
+            flags[:, :, 0, 0])
 
 
 def _fused_fact_kernel_batched(alpha_ref, beta_ref, x_ref, xsq_ref, y_ref,
@@ -1718,14 +1767,14 @@ def _fused_fact_kernel_batched(alpha_ref, beta_ref, x_ref, xsq_ref, y_ref,
 
     gb_ref[...] = jnp.zeros_like(gb_ref)
 
-    tau = tau_ref[...].astype(jnp.float32)               # (TL,)
+    tau = tau_ref[...].astype(jnp.float32)               # (TL, 1)
     v = _verdict_tile(
         z_ref[0], k_ref[0], o_ref[0], act_ref[0],
         dap_ref[0], daf_ref[0], dan_ref[0],
         db_ref[0], sg_ref[0], tau,
     )
     flag = jnp.any(v != ZERO).astype(jnp.int32)
-    flag_ref[0, 0, 0] = flag
+    flag_ref[...] = jnp.reshape(flag, (1, 1))
 
     @pl.when(flag != 0)
     def _compute_ffb():
@@ -1738,7 +1787,7 @@ def _fused_fact_kernel_batched(alpha_ref, beta_ref, x_ref, xsq_ref, y_ref,
             ysq_ref[0].astype(jnp.float32),              # (TN,)
         )
         t, psi = _gradpsi_tile(alpha, beta, c, tau, gamma=gamma)
-        psi_ref[0, 0, 0] += psi
+        psi_ref[...] += jnp.reshape(psi, (1, 1, 1))
         ga_ref[...] += jnp.sum(t, axis=2)[None]          # (1, TL, g)
         gb_ref[...] = jnp.sum(t, axis=(0, 1))[None, None, :]  # (1, 1, TN)
 
@@ -1789,14 +1838,17 @@ def gradpsi_fused_fact_pallas_batched(
     grid = (B, L // tile_l, n // tile_n)
     assert z.shape == (B, L, n), (z.shape, (B, L, n))
 
-    alpha_g = alpha.reshape(B, L, g)
+    alpha_g = alpha.reshape(B, grid[1], tile_l, g)
     x4 = x.reshape(B, L, g, d)
-    xsq_g = x_sq.reshape(B, L, g)
+    xsq_g = x_sq.reshape(B, grid[1], tile_l, g)
+    rows = functools.partial(group_rows, tile_l=tile_l)
+    tiles = functools.partial(group_tiles, tile_l=tile_l)
 
-    brow = pl.BlockSpec((1, tile_l), lambda b, l, j: (b, l))
-    brow_g = pl.BlockSpec((1, tile_l, g), lambda b, l, j: (b, l, 0))
-    bcol = pl.BlockSpec((1, tile_n), lambda b, l, j: (b, j))
-    bmat = pl.BlockSpec((1, tile_l, tile_n), lambda b, l, j: (b, l, j))
+    brow = pl.BlockSpec((1, None, tile_l, 1), lambda b, l, j: (b, l, 0, 0))
+    brow_g = pl.BlockSpec((1, None, tile_l, g), lambda b, l, j: (b, l, 0, 0))
+    bcol = pl.BlockSpec((None, 1, tile_n), lambda b, l, j: (b, 0, j))
+    bmat = pl.BlockSpec((1, None, tile_l, tile_n),
+                        lambda b, l, j: (b, l, 0, j))
 
     ga_part, gb_part, psi, flags = pl.pallas_call(
         functools.partial(_fused_fact_kernel_batched, gamma=float(gamma)),
@@ -1809,32 +1861,34 @@ def gradpsi_fused_fact_pallas_batched(
             brow_g,                                                # x_sq
             pl.BlockSpec((1, tile_n, d), lambda b, l, j: (b, j, 0)),  # y
             bcol,                                                  # y_sq
-            pl.BlockSpec((tile_l,), lambda b, l, j: (l,)),         # tau
+            pl.BlockSpec((None, tile_l, 1), lambda b, l, j: (l, 0, 0)),  # tau
             bmat, bmat, bmat, bmat,                                # z k o act
             brow, brow, brow,                                      # da norms
             bcol,                                                  # db
             brow,                                                  # sqrt_g
         ],
         out_specs=[
-            pl.BlockSpec((1, tile_l, g), lambda b, l, j: (b, l, 0)),
-            pl.BlockSpec((1, 1, tile_n), lambda b, l, j: (b, l, j)),
+            brow_g,
+            pl.BlockSpec((1, None, 1, tile_n), lambda b, l, j: (b, l, 0, j)),
             pl.BlockSpec((1, 1, 1), lambda b, l, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, l, j: (b, l, j)),
+            pl.BlockSpec((None, None, None, 1, 1),
+                         lambda b, l, j: (b, l, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, L, g), jnp.float32),
-            jax.ShapeDtypeStruct((B, grid[1], n), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], tile_l, g), jnp.float32),
+            jax.ShapeDtypeStruct((B, grid[1], 1, n), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(alpha_g, beta, x4, xsq_g, y, y_sq, tau_g,
-      z, k, o, active.astype(jnp.int8),
-      da_plus, da_full, da_neg, db, sqrt_g)
+    )(alpha_g, beta[:, None, :], x4, xsq_g, y, y_sq[:, None, :],
+      rows(tau_g), tiles(z), tiles(k), tiles(o),
+      tiles(active.astype(jnp.int8)), rows(da_plus), rows(da_full),
+      rows(da_neg), db[:, None, :], rows(sqrt_g))
 
     return (
         ga_part.reshape(B, -1),
-        jnp.sum(gb_part, axis=1),
+        jnp.sum(gb_part[:, :, 0], axis=1),
         psi[:, 0, 0],
-        flags,
+        flags[..., 0, 0],
     )

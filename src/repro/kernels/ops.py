@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles: interpret-mode selection (CPU container -> interpret=True; real TPU
--> compiled Mosaic), padding of L and n up to tile multiples, and assembling
-kernel partials into the (value, grad_alpha, grad_beta) triple the solver
-consumes.  Padded tiles are marked skipped in the flag matrix, so they cost
+Handles: interpret-mode selection (CPU backend -> interpret=True; TPU ->
+compiled Mosaic; any other backend is an error), padding of L and n up to
+tile multiples, and assembling kernel partials into the (value,
+grad_alpha, grad_beta) triple the solver consumes.  Padded tiles are marked skipped in the flag matrix, so they cost
 nothing and contribute exact zeros.
 
 The hot path is structured around two prepared states (DESIGN.md §4):
@@ -60,8 +60,21 @@ from repro.kernels.screen import screen_pallas
 
 
 def default_interpret() -> bool:
-    """Interpret Pallas on anything that is not a real TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU backend, compiled Mosaic on a TPU.
+
+    Any other backend raises: the kernels are Mosaic TPU kernels, and
+    silently interpreting them elsewhere would hide that the device path
+    never ran.
+    """
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels need a TPU (compiled) or the CPU (interpreted); "
+        f"the default JAX backend is {backend!r}"
+    )
 
 
 def _pad_tau(tau, L: int, tile_l: int) -> jnp.ndarray:
@@ -347,6 +360,18 @@ def prepare_padded_problem_batched(
         L=L, g=g, n=n, L_pad=L_pad, n_pad=n_pad,
         tile_l=tile_l, tile_n=tile_n,
     )
+
+
+def is_tile_aligned(prob: DualProblem, dtype,
+                    tile_n: int = DEFAULT_TILE_N) -> bool:
+    """True when the kernels' tile padding leaves a cost of ``prob`` as is.
+
+    :func:`prepare_padded_problem_batched` then returns a copy of ``C``
+    with the same shape and values, which a caller need not keep.
+    """
+    tile_l = resolve_tile_l(prob.num_groups, prob.group_size, tile_n,
+                            jnp.dtype(dtype).itemsize)
+    return prob.tile_padded_shape(tile_l, tile_n) == (prob.num_groups, prob.n)
 
 
 def pad_screen_state_batched(

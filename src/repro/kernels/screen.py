@@ -32,6 +32,8 @@ from repro.kernels.gradpsi import (
     _record_launch,
     _verdict_tile,
     factorized_cost_tile,
+    group_rows,
+    group_tiles,
     tau_row,
 )
 
@@ -43,17 +45,19 @@ def _kernel_full(z_ref, k_ref, o_ref, act_ref, dap_ref, daf_ref, dan_ref,
     # standalone and fused flag outputs bitwise-interchangeable.
     v = _verdict_tile(z_ref[...], k_ref[...], o_ref[...], act_ref[...],
                       dap_ref[...], daf_ref[...], dan_ref[...],
-                      db_ref[...], sg_ref[...], tau_ref[...])
+                      db_ref[0], sg_ref[...], tau_ref[...])
     verdict_ref[...] = v
-    flag_ref[0, 0] = jnp.any(v != ZERO).astype(jnp.int32)
+    flag_ref[...] = jnp.reshape(
+        jnp.any(v != ZERO).astype(jnp.int32), (1, 1))
 
 
 def _kernel_flags(z_ref, k_ref, o_ref, act_ref, dap_ref, daf_ref, dan_ref,
                   db_ref, sg_ref, tau_ref, flag_ref):
     v = _verdict_tile(z_ref[...], k_ref[...], o_ref[...], act_ref[...],
                       dap_ref[...], daf_ref[...], dan_ref[...],
-                      db_ref[...], sg_ref[...], tau_ref[...])
-    flag_ref[0, 0] = jnp.any(v != ZERO).astype(jnp.int32)
+                      db_ref[0], sg_ref[...], tau_ref[...])
+    flag_ref[...] = jnp.reshape(
+        jnp.any(v != ZERO).astype(jnp.int32), (1, 1))
 
 
 @functools.partial(
@@ -89,23 +93,27 @@ def screen_pallas(
     assert L % tile_l == 0 and n % tile_n == 0, (L, tile_l, n, tile_n)
     grid = (L // tile_l, n // tile_n)
     tau_g = tau_row(tau, L)
+    rows = functools.partial(group_rows, tile_l=tile_l)
+    tiles = functools.partial(group_tiles, tile_l=tile_l)
 
-    row = pl.BlockSpec((tile_l,), lambda l, j: (l,))
-    col = pl.BlockSpec((tile_n,), lambda l, j: (j,))
-    mat = pl.BlockSpec((tile_l, tile_n), lambda l, j: (l, j))
-    flag = pl.BlockSpec((1, 1), lambda l, j: (l, j))
+    # block layouts: see "Mosaic block layouts" in gradpsi.py
+    row = pl.BlockSpec((None, tile_l, 1), lambda l, j: (l, 0, 0))
+    col = pl.BlockSpec((1, tile_n), lambda l, j: (0, j))
+    mat = pl.BlockSpec((None, tile_l, tile_n), lambda l, j: (l, 0, j))
+    flag = pl.BlockSpec((None, None, 1, 1), lambda l, j: (l, j, 0, 0))
+    flag_shape = jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32)
 
     if emit_verdict:
         kernel = _kernel_full
         out_specs = [mat, flag]
         out_shape = [
-            jax.ShapeDtypeStruct((L, n), jnp.int32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], tile_l, n), jnp.int32),
+            flag_shape,
         ]
     else:
         kernel = _kernel_flags
         out_specs = [flag]
-        out_shape = [jax.ShapeDtypeStruct(grid, jnp.int32)]
+        out_shape = [flag_shape]
 
     outs = pl.pallas_call(
         kernel,
@@ -114,12 +122,14 @@ def screen_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(z_snap, k_snap, o_snap, active.astype(jnp.int8),
-      da_plus, da_full, da_neg, db, sqrt_g, tau_g)
+    )(tiles(z_snap), tiles(k_snap), tiles(o_snap),
+      tiles(active.astype(jnp.int8)), rows(da_plus), rows(da_full),
+      rows(da_neg), db[None, :], rows(sqrt_g), rows(tau_g))
 
+    flags = outs[-1][:, :, 0, 0]
     if emit_verdict:
-        return outs[0], outs[1]
-    return None, outs[0]
+        return outs[0].reshape(L, n), flags
+    return None, flags
 
 
 # -- factorized snapshot-norms kernel (materialization-free route) -------------
@@ -138,12 +148,13 @@ def _snapshot_kernel_fact(alpha_ref, beta_ref, x_ref, xsq_ref, y_ref, ysq_ref,
         x_ref[...].astype(jnp.float32),                  # (TL, g, d)
         xsq_ref[...].astype(jnp.float32),                # (TL, g)
         y_ref[...].astype(jnp.float32),                  # (TN, d)
-        ysq_ref[...].astype(jnp.float32),                # (TN,)
+        ysq_ref[0].astype(jnp.float32),                  # (TN,)
     )
     f = (alpha_ref[...].astype(jnp.float32)[:, :, None]
-         + beta_ref[...].astype(jnp.float32)[None, None, :]
+         + beta_ref[0].astype(jnp.float32)[None, None, :]
          - c)                                            # (TL, g, TN)
-    fm = jnp.where(mask_ref[...][:, :, None] != 0, f, 0.0)
+    mask = mask_ref[...].astype(jnp.float32)[:, :, None]    # (TL, g, 1)
+    fm = jnp.where(mask != 0, f, 0.0)
     z_ref[...] = jnp.sqrt(jnp.sum(jnp.square(jnp.maximum(fm, 0.0)), axis=1))
     k_ref[...] = jnp.sqrt(jnp.sum(jnp.square(fm), axis=1))
     o_ref[...] = jnp.sqrt(jnp.sum(jnp.square(jnp.minimum(fm, 0.0)), axis=1))
@@ -183,14 +194,16 @@ def snapshot_norms_fact_pallas(
     assert L % tile_l == 0 and n_pad % tile_n == 0, (L, tile_l, n_pad, tile_n)
     grid = (L // tile_l, n_pad // tile_n)
 
-    alpha_g = alpha.reshape(L, g)
+    Lt = L // tile_l
+    alpha_g = alpha.reshape(Lt, tile_l, g)
     x3 = x.reshape(L, g, d)
-    xsq_g = x_sq.reshape(L, g)
-    mask_g = mask.reshape(L, g).astype(jnp.int8)
+    xsq_g = x_sq.reshape(Lt, tile_l, g)
+    mask_g = mask.reshape(Lt, tile_l, g).astype(jnp.int8)
 
-    row_g = pl.BlockSpec((tile_l, g), lambda l, j: (l, 0))
-    col = pl.BlockSpec((tile_n,), lambda l, j: (j,))
-    mat = pl.BlockSpec((tile_l, tile_n), lambda l, j: (l, j))
+    # block layouts: see "Mosaic block layouts" in gradpsi.py
+    row_g = pl.BlockSpec((None, tile_l, g), lambda l, j: (l, 0, 0))
+    col = pl.BlockSpec((1, tile_n), lambda l, j: (0, j))
+    mat = pl.BlockSpec((None, tile_l, tile_n), lambda l, j: (l, 0, j))
 
     z, k, o = pl.pallas_call(
         _snapshot_kernel_fact,
@@ -205,12 +218,8 @@ def snapshot_norms_fact_pallas(
             row_g,                                           # mask
         ],
         out_specs=[mat, mat, mat],
-        out_shape=[
-            jax.ShapeDtypeStruct((L, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((L, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((L, n_pad), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((Lt, tile_l, n_pad), jnp.float32)] * 3,
         interpret=interpret,
-    )(alpha_g, beta, x3, xsq_g, y, y_sq, mask_g)
+    )(alpha_g, beta[None, :], x3, xsq_g, y, y_sq[None, :], mask_g)
 
-    return z, k, o
+    return z.reshape(L, n_pad), k.reshape(L, n_pad), o.reshape(L, n_pad)

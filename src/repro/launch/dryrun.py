@@ -1,9 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-# ^ MUST precede any jax import/init: jax locks the device count on first use.
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
+# ^ MUST precede any jax init: jax locks the device count on first use.  Only
+# when run as a script: importing the HLO helpers below (tests, benchmarks)
+# must not turn the importing process into a 512-device host.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver:

@@ -285,6 +285,8 @@ class _Bucket:
             self._device = arrs
             self._padded = None
             if self.opts.grad_impl in ("pallas", "fused"):
+                from repro.kernels import ops as kops
+
                 if self.mesh is not None:
                     from repro.core import sharded as shd
 
@@ -292,7 +294,12 @@ class _Bucket:
                         self._device[0], self.prob, self.mesh,
                         precision=self.opts.precision,
                     )
-                else:
+                elif (self.opts.precision != "f32"
+                      or not kops.is_tile_aligned(self.prob, self.C.dtype)):
+                    # an f32 tile-aligned bucket keeps no padded copy: the
+                    # round program pads C for free, and a copy would double
+                    # the bucket's largest buffer (|L| = 1280, n = 12,800
+                    # and 4 slots then exceed the 16 GB of a v5e)
                     self._padded = slv._prepare_padded(
                         self._device[0], self.prob, self.opts
                     )

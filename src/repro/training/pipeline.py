@@ -97,9 +97,7 @@ def gpipe_forward(
         return outputs
 
     spec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    from repro.utils.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(spec_params, P()),      # stages sharded; input replicated

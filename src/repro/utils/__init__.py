@@ -4,7 +4,7 @@ from repro.utils.tree import (
     tree_global_norm,
     tree_zeros_like,
 )
-from repro.utils.compat import make_mesh, mesh_axis_types_kwargs
+from repro.utils.compat import make_mesh
 from repro.utils.logging import get_logger
 
 __all__ = [
@@ -13,6 +13,5 @@ __all__ = [
     "tree_global_norm",
     "tree_zeros_like",
     "make_mesh",
-    "mesh_axis_types_kwargs",
     "get_logger",
 ]

@@ -1,10 +1,7 @@
-"""Version-compatibility shims for JAX APIs that moved across releases.
+"""Mesh construction for the pinned JAX release.
 
-``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-``jax.make_mesh``) only exists in newer JAX releases; older ones create
-meshes with implicitly-auto axes and reject the kwarg.  Everything in the
-repo that builds a mesh goes through :func:`make_mesh` so the version probe
-lives in exactly one place.
+Everything in the repo that builds a mesh goes through :func:`make_mesh`,
+so the axis-type policy lives in exactly one place.
 """
 from __future__ import annotations
 
@@ -13,34 +10,10 @@ from typing import Sequence
 import jax
 
 
-def mesh_axis_types_kwargs(num_axes: int) -> dict:
-    """``{"axis_types": (Auto,) * num_axes}`` when supported, else ``{}``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with explicitly-Auto axes where the API allows it."""
-    shape = tuple(shape)
+    """``jax.make_mesh`` with explicitly-Auto axes (sharding-in-types off)."""
     axis_names = tuple(axis_names)
     return jax.make_mesh(
-        shape, axis_names, **mesh_axis_types_kwargs(len(axis_names))
-    )
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` where it exists; the legacy experimental entry point
-    (whose replication-check kwarg is spelled ``check_rep``) otherwise."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    return _legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        tuple(shape), axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
     )

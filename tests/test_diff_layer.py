@@ -7,8 +7,9 @@ Three independent referees certify ``jax.grad`` of the layer:
     regenerates them) — the strongest oracle, backend-free;
   * AD through :func:`repro.ot.diff.unrolled_value` — a plain dual-ascent
     solver written so JAX *can* differentiate through it;
-  * bitwise cross-backend agreement — every grad_impl solves the same
-    padded problem, so the refined layer value must be bit-identical.
+  * cross-backend agreement — every grad_impl solves the same padded
+    problem: the Pallas backends refine to the same value bit for bit,
+    the XLA reference backends to within a few f32 ulps of it.
 
 Plus the stochastic minibatch solver's contract: deterministic given its
 seed, and converging to the exact L-BFGS objective on the golden problem.
@@ -27,7 +28,8 @@ from repro.core.regularizers import GroupSparseReg
 from repro.ot import diff
 from tests.conftest import FIXTURE_DIR
 
-# (grad_impl, pallas_impl) combos that must agree bitwise and match FD
+# (grad_impl, pallas_impl) combos that must match FD; the kernel ones
+# (the last three) must also agree bitwise
 BACKENDS = [
     ("dense", "auto"),
     ("screened", "auto"),
@@ -35,6 +37,7 @@ BACKENDS = [
     ("pallas", "compact"),
     ("fused", "grid"),
 ]
+KERNEL_BACKENDS = BACKENDS[2:]
 
 # the FD harness needs the dual residual at the f32 noise floor; the plain
 # f32 L-BFGS line search stalls around ||g||~1e-4, so the layer appends
@@ -97,17 +100,48 @@ def test_layer_value_bitwise_equals_executor(golden):
         assert float(v) == float(sol.value), (grad_impl, pallas_impl)
 
 
-def test_refined_value_bitwise_across_backends(golden):
-    """All five backends refine to the SAME f32 value, bit for bit, and it
-    sits on the committed f64 optimum."""
+@pytest.fixture(scope="module")
+def refined_values(golden):
+    """The refined layer value of the golden dense problem, per backend."""
     C, L, g, n, reg = _dense_problem(golden)
-    vals = []
-    for grad_impl, pallas_impl in BACKENDS:
-        layer = _layer(L, g, n, reg, grad_impl, pallas_impl,
-                       grad_refine=REFINE_DENSE)
-        vals.append(float(layer(C)))
-    assert len(set(vals)) == 1, vals
-    assert vals[0] == pytest.approx(golden["dense"]["value_f64"], abs=5e-6)
+    return {
+        (grad_impl, pallas_impl): np.float32(
+            _layer(L, g, n, reg, grad_impl, pallas_impl,
+                   grad_refine=REFINE_DENSE)(C)
+        )
+        for grad_impl, pallas_impl in BACKENDS
+    }
+
+
+def test_refined_value_bitwise_across_backends(golden, refined_values):
+    """The three Pallas-kernel backends refine to the same f32 value, bit
+    for bit, and it sits on the committed f64 optimum.
+
+    They share one kernel body, so their oracles run one op sequence and
+    their L-BFGS trajectories never part.
+    """
+    vals = {refined_values[bk] for bk in KERNEL_BACKENDS}
+    assert len(vals) == 1, vals
+    assert vals.pop() == pytest.approx(golden["dense"]["value_f64"], abs=5e-6)
+
+
+def test_refined_value_reference_backends_within_ulps(golden, refined_values):
+    """``dense`` and ``screened`` land a few f32 ulps from the kernels.
+
+    Their oracles are XLA reductions whose summation order XLA picks per
+    fusion, and that choice depends on the program around them: on the
+    CPU the same dense oracle gives other gradient bits inside the solve
+    than alone.  So their L-BFGS trajectories part from the kernels' after
+    a few iterations, and the refine keeps each end point's offset along
+    the dual's flat direction (alpha + t, beta - t).  Readings on the CPU:
+    dense +2 ulps, screened +1 ulp from the kernels' value.
+    """
+    kernel = refined_values[KERNEL_BACKENDS[0]]
+    for bk in BACKENDS[:2]:
+        v = refined_values[bk]
+        assert abs(int(v.view(np.int32)) - int(kernel.view(np.int32))) <= 4, (
+            bk, v, kernel)
+        assert v == pytest.approx(golden["dense"]["value_f64"], abs=5e-6)
 
 
 # -- dense cost: Danskin grad vs committed f64 FD, every backend --------------

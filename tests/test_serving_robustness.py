@@ -7,6 +7,11 @@ plans of retired-DONE requests are bitwise identical to a no-fault run of
 the same healthy requests.
 """
 import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -27,6 +32,21 @@ from repro.utils.faults import REGISTRY, FaultSpec, injected
 
 OPTS = SolveOptions(grad_impl="screened", lbfgs=LbfgsOptions(max_iters=150))
 REG = GroupSparseReg.from_rho(1.0, 0.6)
+SRC = str(Path(__file__).resolve().parents[1] / "src")
+
+
+def _run_on_host_devices(code: str, devices: int, timeout: int = 600):
+    """Run ``code`` in a child whose CPU backend has ``devices`` devices."""
+    env = dict(
+        os.environ,
+        XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+        PYTHONPATH=SRC,
+        JAX_PLATFORMS="cpu",
+    )
+    return subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(code)],
+        env=env, capture_output=True, text=True, timeout=timeout,
+    )
 
 
 @pytest.fixture(autouse=True)
@@ -316,34 +336,51 @@ def test_pending_queue_unit_behavior():
 
 def test_failed_slot_keeps_done_neighbour_bitwise_sharded():
     """The quarantine bitwise guarantee must hold across a device mesh too
-    (slots on other devices are frozen through the same masked merges)."""
-    import jax
+    (slots on other devices are frozen through the same masked merges).
 
-    if jax.device_count() < 2:
-        pytest.skip("needs a multi-device host (CI chaos job forces 4)")
-    from repro.core.distributed import make_batch_mesh
+    Runs in a child process with 4 forced host devices: the device count
+    must be set before jax initializes (same pattern as test_sharded.py).
+    """
+    code = """
+        import numpy as np, jax
+        from repro.core.distributed import make_batch_mesh
+        from repro.core.lbfgs import LbfgsOptions
+        from repro.core.regularizers import GroupSparseReg
+        from repro.core.solver import SolveOptions
+        from repro.serving.ot_engine import OTRequest, OTServingEngine
+        from repro.serving.policy import RequestStatus, ServingPolicy
+        from repro.utils.faults import FaultSpec, injected
 
-    rng = np.random.default_rng(15)
-    C0 = rng.random((24, 30)).astype(np.float32)
-    labels = np.repeat(np.arange(4), 6)
-    policy = ServingPolicy(fallback_ladder=("restart",), max_attempts=2)
+        assert jax.device_count() == 4, jax.device_count()
+        OPTS = SolveOptions(grad_impl="screened",
+                            lbfgs=LbfgsOptions(max_iters=150))
+        REG = GroupSparseReg.from_rho(1.0, 0.6)
+        rng = np.random.default_rng(15)
+        C0 = rng.random((24, 30)).astype(np.float32)
+        C1 = rng.random((24, 30)).astype(np.float32)
+        labels = np.repeat(np.arange(4), 6)
+        policy = ServingPolicy(fallback_ladder=("restart",), max_attempts=2)
 
-    ref_engine = OTServingEngine(REG, OPTS, max_batch=1,
+        ref_engine = OTServingEngine(REG, OPTS, max_batch=1,
+                                     mesh=make_batch_mesh(), policy=policy)
+        ref = ref_engine.run([OTRequest(rid=0, C=C0, labels=labels)])[0]
+
+        engine = OTServingEngine(REG, OPTS, max_batch=1,
                                  mesh=make_batch_mesh(), policy=policy)
-    ref = ref_engine.run([OTRequest(rid=0, C=C0, labels=labels)])[0]
-
-    engine = OTServingEngine(REG, OPTS, max_batch=1,
-                             mesh=make_batch_mesh(), policy=policy)
-    with injected(FaultSpec("nan_cost", rids={1})):
-        done = engine.run([
-            OTRequest(rid=0, C=C0, labels=labels),
-            _request(rng, 1),
-        ])
-    by_rid = {r.rid: r for r in done}
-    assert by_rid[1].status is RequestStatus.FAILED
-    assert by_rid[0].status is RequestStatus.DONE
-    assert by_rid[0].value == ref.value       # bitwise across the mesh
-    np.testing.assert_array_equal(by_rid[0].plan, ref.plan)
+        with injected(FaultSpec("nan_cost", rids={1})):
+            done = engine.run([
+                OTRequest(rid=0, C=C0, labels=labels),
+                OTRequest(rid=1, C=C1, labels=labels),
+            ])
+        by_rid = {r.rid: r for r in done}
+        assert by_rid[1].status is RequestStatus.FAILED
+        assert by_rid[0].status is RequestStatus.DONE
+        assert by_rid[0].value == ref.value       # bitwise across the mesh
+        np.testing.assert_array_equal(by_rid[0].plan, ref.plan)
+        print("OK")
+    """
+    r = _run_on_host_devices(code, devices=4)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-4000:]
 
 
 # -- chaos: everything at once -------------------------------------------------
