@@ -1,0 +1,11 @@
+"""The benchmark's tests run on the CPU, with the system's kernels in
+interpret mode: ``python -m pytest bench/tests``."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH.parent / "src"), str(BENCH), str(BENCH / "tests")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
