@@ -3,6 +3,12 @@
 Implemented in log-space (stabilized; Schmitzer 2019) because the paper
 explicitly notes that the plain Sinkhorn iteration was numerically unstable
 across most of their hyperparameter grid.  Pure JAX, jit/shard-friendly.
+
+The loop's phases and the final plan run under named scopes
+(``sinkhorn.f_update``, ``sinkhorn.g_update``, ``sinkhorn.marginal_err``,
+``sinkhorn.plan``).  They reach the compiled program only as the
+``op_name`` metadata of its instructions, so a profile can attribute device
+time to them; the persistent-cache key ignores them.
 """
 from __future__ import annotations
 
@@ -11,6 +17,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.utils import trace  # noqa: F401  (counts the programs it builds)
 
 
 class SinkhornResult(NamedTuple):
@@ -37,14 +45,17 @@ def sinkhorn_log(
     def body(carry):
         f, g, it, err = carry
         # f-update: f_i = -eps logsumexp_j ((g_j - C_ij)/eps) + eps log a_i
-        Mf = (g[None, :] - C) / eps
-        f = eps * (loga - jax.scipy.special.logsumexp(Mf, axis=1))
-        Mg = (f[:, None] - C) / eps
-        g = eps * (logb - jax.scipy.special.logsumexp(Mg, axis=0))
+        with jax.named_scope("sinkhorn.f_update"):
+            Mf = (g[None, :] - C) / eps
+            f = eps * (loga - jax.scipy.special.logsumexp(Mf, axis=1))
+        with jax.named_scope("sinkhorn.g_update"):
+            Mg = (f[:, None] - C) / eps
+            g = eps * (logb - jax.scipy.special.logsumexp(Mg, axis=0))
         # marginal error of the implied plan
-        logT = (f[:, None] + g[None, :] - C) / eps
-        row = jnp.exp(jax.scipy.special.logsumexp(logT, axis=1))
-        err = jnp.sum(jnp.abs(row - a))
+        with jax.named_scope("sinkhorn.marginal_err"):
+            logT = (f[:, None] + g[None, :] - C) / eps
+            row = jnp.exp(jax.scipy.special.logsumexp(logT, axis=1))
+            err = jnp.sum(jnp.abs(row - a))
         return f, g, it + 1, err
 
     def cond(carry):
@@ -56,5 +67,6 @@ def sinkhorn_log(
     f, g, it, err = jax.lax.while_loop(
         cond, body, (f0, g0, jnp.zeros((), jnp.int32), jnp.asarray(jnp.inf))
     )
-    plan = jnp.exp((f[:, None] + g[None, :] - C) / eps)
+    with jax.named_scope("sinkhorn.plan"):
+        plan = jnp.exp((f[:, None] + g[None, :] - C) / eps)
     return SinkhornResult(f=f, g=g, plan=plan, n_iters=it, err=err)

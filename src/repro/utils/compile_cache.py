@@ -2,7 +2,10 @@
 
 Scripts (``chip_smoke.py``, ``benchmarks/run.py``, ``examples/``) call
 :func:`enable_compile_cache` once at start-up; library modules never do,
-so importing ``repro`` changes no global JAX setting.
+so importing ``repro`` changes no global JAX setting.  Importing this
+module starts the build recorder (``repro.utils.trace``), so a script
+that turns the cache on also has its hits and misses counted from the
+start.
 """
 from __future__ import annotations
 
@@ -10,6 +13,8 @@ import os
 from pathlib import Path
 
 import jax
+
+from repro.utils import trace  # noqa: F401  (records builds from start-up)
 
 # A fixed path: a cache written to a directory that changes between runs
 # is never found again.
