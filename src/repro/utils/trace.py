@@ -12,12 +12,18 @@ build can be placed before or after any moment they took on that clock.
 The cost is one list append per event: about a hundred while a process
 builds a solver's program (each jitted ``jnp`` function it calls is traced
 too), and none when a built program runs.
+
+A solver that picks a route while it traces records it with
+:func:`record_route` (``sinkhorn_log``: the VMEM-resident kernel or the XLA
+loop); :func:`routes` lists them.  JAX traces before it looks the program
+up in the persistent cache, so every process that builds the solver
+records its route, also where the compiled program comes from the cache.
 """
 from __future__ import annotations
 
 import threading
 import time
-from typing import List, NamedTuple
+from typing import List, NamedTuple, Tuple
 
 from jax import monitoring
 
@@ -91,3 +97,28 @@ monitoring.register_event_listener(_on_event)
 def builds() -> List[Build]:
     """Every record so far, in the order JAX reported them."""
     return list(_records)
+
+
+class Route(NamedTuple):
+    """The route one trace of a solver took: ``fun_name`` is the solver's
+    name (``sinkhorn_log``), ``route`` the one it took (``"resident"`` or
+    ``"xla"`` for ``sinkhorn_log``), ``shape`` the shape it was traced at."""
+
+    fun_name: str
+    route: str
+    shape: Tuple[int, ...]
+
+
+_routes: List[Route] = []
+
+
+def record_route(fun_name: str, route: str, shape) -> None:
+    """Record the route of one trace; called by the solver while it traces,
+    so each program it builds records it once, also where the compiled
+    program then comes from the persistent cache."""
+    _routes.append(Route(fun_name, route, tuple(int(d) for d in shape)))
+
+
+def routes() -> List[Route]:
+    """Every route recorded so far, in the order the traces took them."""
+    return list(_routes)

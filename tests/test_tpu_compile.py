@@ -6,7 +6,9 @@ layouts that disagree with XLA's, scalar stores to VMEM, unsupported
 reshapes, or more VMEM than a kernel may use.  These tests compile each
 kernel ahead of time for one chip of a described ``v5e:2x2`` topology at
 the paper's widths (|L| = 1280 groups, g = 10 padded to 16, m = n =
-12,800), which needs the TPU compiler but no TPU.
+12,800), which needs the TPU compiler but no TPU.  The VMEM-resident
+Sinkhorn kernel compiles at the benchmark's m = n = 3,200 and at the
+largest square cost its VMEM budget admits.
 
 The topology is described only inside the module-scoped fixture below,
 never at import: the TPU library can be loaded by one process at a time,
@@ -20,6 +22,7 @@ import pytest
 
 from repro.kernels import gradpsi as gp
 from repro.kernels import screen as sc
+from repro.kernels import sinkhorn as ks
 
 L, G, N, D, B = 1280, 16, 12800, 16, 4        # paper widths; d, B for variants
 TILE_N = gp.DEFAULT_TILE_N
@@ -88,6 +91,11 @@ def _lower(name, sds):
             sds((m,), f32), sds((N,), f32), sds((m, D), f32), sds((m,), f32),
             sds((N, D), f32), sds((N,), f32), *screen_ops(),
             **dict(kw, tile_l=tlf))
+    if name.startswith("sinkhorn_resident"):
+        side = {"sinkhorn_resident": 3200, "sinkhorn_resident_budget": 4736}[name]
+        return ks.sinkhorn_resident.lower(
+            sds((side, side), f32), sds((side,), f32), sds((side,), f32),
+            sds((side,), f32), sds((), f32), sds((), f32), max_iters=2000)
     raise KeyError(name)
 
 
@@ -100,12 +108,15 @@ def _lower(name, sds):
     "gradpsi_pallas_compact_batched",
     "gradpsi_fused_pallas_batched",
     "gradpsi_fused_fact_pallas",
+    "sinkhorn_resident",
+    "sinkhorn_resident_budget",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name):
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
     # the compact kernels' compile also accepts their VMEM request of
-    # COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES
+    # COMPACT_PIPELINE_BUFFERS * VMEM_BUDGET_BYTES, and the resident Sinkhorn
+    # kernel's of its cost plus working set (kernels/sinkhorn.vmem_bytes)
     compiled = _lower(name, sds).compile()
     # a Mosaic kernel, not an interpreted loop, is in the program
     assert "tpu_custom_call" in compiled.as_text()

@@ -1,7 +1,9 @@
 """The build recorder (``repro.utils.trace``) and the Sinkhorn solver's
 phase scopes: the recorder sees the persistent cache miss and then hit, on
 the ``perf_counter`` clock; the scopes reach the compiled program's
-metadata and leave the program and its persistent-cache key as they were."""
+metadata and leave the program and its persistent-cache key as they were.
+A cost too large for the resident kernel's VMEM keeps the XLA loop and
+its scopes on a TPU too."""
 import contextlib
 import time
 
@@ -97,3 +99,19 @@ def test_compiled_solver_names_its_phases_in_metadata():
     loop = [f'op_name="jit(sinkhorn_log)/while/body/{s}/' for s in SCOPES[:3]]
     assert all(s in text for s in loop)
     assert 'op_name="jit(sinkhorn_log)/while/body/sinkhorn.plan' not in text
+
+
+def test_cost_over_the_vmem_budget_keeps_the_xla_loop(monkeypatch):
+    """At the paper's width (m = n = 12,800, 655 MB) the cost does not fit
+    VMEM, so on a TPU too ``sinkhorn_log`` compiles the XLA ``while`` loop
+    with its three loop scopes, and no kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    n0 = len(trace.routes())
+    text = _lowered(12800, 12800).compile().as_text()
+    jax.clear_caches()
+    assert trace.routes()[n0:] == [trace.Route("sinkhorn_log", "xla", (12800, 12800))]
+    assert "while(" in text
+    assert "sinkhorn_resident" not in text and "custom_call" not in text
+    for s in SCOPES[:3]:
+        assert f'op_name="jit(sinkhorn_log)/while/body/{s}/' in text, s
